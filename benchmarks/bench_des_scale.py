@@ -54,6 +54,7 @@ import resource
 import sys
 import time
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import ComputeResource, EdgeToCloudPipeline, PilotManager
 from repro.core.executor import SimExecutor
 from repro.core.monitoring import MetricsRegistry
@@ -131,7 +132,7 @@ def run_cell(*, arrival: str, messages: int, devices: int, consumers: int,
         _reset_peak_rss()
     clock = SimClock()
     metrics = MetricsRegistry(clock=clock, streaming=streaming)
-    mgr = PilotManager()
+    mgr = PilotManager(devices=())
     edge = mgr.submit_pilot(ComputeResource(tier="edge", n_workers=devices))
     cloud = mgr.submit_pilot(
         ComputeResource(tier="cloud", n_workers=consumers))
@@ -399,4 +400,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

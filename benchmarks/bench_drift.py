@@ -30,6 +30,7 @@ import sys
 import time
 from dataclasses import replace
 
+from repro.compile_cache import enable_compilation_cache
 from repro.cost.readvisor import ReAdviseSpec
 from repro.sim.scenarios import DriftSpec, Scenario, run_scenario
 from repro.sim.shard import DRIFT_PARITY_COLS, run_drift_sharded
@@ -177,4 +178,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import (ComputeResource, EdgeToCloudPipeline, PilotManager,
                         WanShaper)
 from repro.core.executor import SimExecutor
@@ -131,4 +132,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

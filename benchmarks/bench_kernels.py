@@ -31,6 +31,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compilation_cache
 from repro.kernels.kmeans import autotune_block_n
 from repro.ml.kmeans import PRECISIONS, _assign, _assign_update
 
@@ -212,4 +213,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import ComputeResource, EdgeToCloudPipeline, PilotManager
 from repro.ml import AutoEncoder, IsolationForest, KMeans, MiniAppGenerator
 from repro.ml.datagen import message_nbytes
@@ -135,4 +136,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

@@ -11,6 +11,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import ComputeResource, EdgeToCloudPipeline, PilotManager
 from repro.ml import MiniAppGenerator, message_nbytes
 from repro.ml.datagen import PAPER_POINTS
@@ -81,4 +82,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

@@ -26,6 +26,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compilation_cache
 from repro.cost.advisor import PlacementAdvisor
 from repro.sim.scenarios import MODELS, PLACEMENTS, WAN_BANDS
 
@@ -121,4 +122,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

@@ -25,6 +25,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compilation_cache
 from repro.sim.scenarios import (AUTOENCODER, KMEANS, MODELS, PLACEMENTS,
                                  FailureSpec, Scenario, format_table,
                                  run_scenario, sweep)
@@ -99,4 +100,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

@@ -17,6 +17,7 @@ import sys
 import numpy as np
 
 from benchmarks import bench_geo, bench_models, bench_pipeline
+from repro.compile_cache import enable_compilation_cache
 
 
 def validate_claims(model_rows):
@@ -120,4 +121,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     sys.exit(main())

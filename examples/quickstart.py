@@ -55,3 +55,6 @@ for hop, stats in result.per_hop().items():
 outliers = sum(r["n_outliers"] for r in result.results)
 print(f"outliers flagged across stream: {outliers}")
 manager.release_all()
+if result.n_processed != result.n_produced:
+    raise SystemExit(f"lost {result.n_produced - result.n_processed} "
+                     f"messages: {result.metrics.events('task_error')[:1]}")

@@ -339,16 +339,17 @@ class ThreadedExecutor:
         runtimes = [TaskRuntime(stage.pilot, pipe.metrics,
                                 interpreter=interpret, **runtime_kw)
                     for stage in pipe.stages]
-        producer_futs = [
+        # every task future, per stage index (the re-advisory thread
+        # appends replacement fleets to its stage's list)
+        stage_futs = {0: [
             runtimes[0].submit(pipe._source_body, state, i,
                                state.per_device[i])
-            for i in range(pipe.stage_tasks(0))]
-        consumer_futs = []
+            for i in range(pipe.stage_tasks(0))]}
         for si in range(1, len(pipe.stages)):
-            consumer_futs.extend(
+            stage_futs[si] = [
                 runtimes[si].submit(pipe._stage_body, state, si,
                                     pipe.stage_cid(si, i))
-                for i in range(pipe.stage_tasks(si)))
+                for i in range(pipe.stage_tasks(si))]
 
         # online re-advisory: a daemon monitor thread ticks the attached
         # ReAdvisor against the wall clock; a decision re-binds the
@@ -393,7 +394,7 @@ class ThreadedExecutor:
                     for _ in range(pipe.stage_tasks(rv_si)):
                         cid = pipe.stage_cid(rv_si, next(stage_seq[rv_si]))
                         pipe.metrics.event("consumer_spawned", consumer=cid)
-                        consumer_futs.append(
+                        stage_futs[rv_si].append(
                             rt.submit(pipe._stage_body, state, rv_si, cid))
                     rv.applied(dec, clock.now())
 
@@ -404,23 +405,30 @@ class ThreadedExecutor:
         # the semaphore wait is real (worker threads are real) but the
         # deadline is measured on the injected clock; with a virtual clock
         # the real wait must stay short so deadline advances (driven from
-        # another thread) are observed promptly
+        # another thread) are observed promptly.  A stage whose every task
+        # has failed (retries spent) can never deliver the rest, so the
+        # run ends there instead of at the deadline.
         deadline = t0 + timeout_s
         remaining = n_messages
         while remaining > 0:
-            wait_s = min(deadline - clock.now(), timeout_s)
-            if clock.virtual:
-                wait_s = min(wait_s, 0.05)
+            wait_s = min(deadline - clock.now(), timeout_s,
+                         0.05 if clock.virtual else 0.25)
             if state.processed_sem.acquire(timeout=max(wait_s, 0.01)):
                 remaining -= 1
             elif clock.now() >= deadline:
                 break
+            else:
+                dead = _failed_stage(stage_futs)
+                if dead is not None:
+                    pipe.metrics.event("run_aborted",
+                                       stage=pipe.stages[dead].name)
+                    break
         state.stop.set()
         wall = (state.t_done if state.t_done is not None
                 else clock.now()) - t0     # before any shutdown nudging
         if rv_thread is not None:
             rv_thread.join(timeout=5.0)
-        for f in producer_futs + consumer_futs:
+        for f in [f for futs in stage_futs.values() for f in futs]:
             # with a manual virtual clock, workers may be parked inside
             # clock.sleep waiting for time the external driver will never
             # provide once the run is over — tick the clock while joining
@@ -438,6 +446,17 @@ class ThreadedExecutor:
         for rt in runtimes:
             rt.shutdown(wait=False)
         return pipe._finish(state, wall)
+
+
+def _failed_stage(stage_futs: Dict[int, List[Any]]) -> Optional[int]:
+    """Index of a stage with no task left running and at least one task
+    failed for good, or None."""
+    for si, futs in stage_futs.items():
+        futs = list(futs)
+        if (futs and all(f.done() for f in futs)
+                and any(f.failed() for f in futs)):
+            return si
+    return None
 
 
 # ---------------------------------------------------------------------------

@@ -130,6 +130,11 @@ class PilotManager:
     The manager never runs workload code — that is the decoupling the paper's
     abstraction is built on. The FaaS layer (core/faas.py) binds functions to
     pilots *after* acquisition.
+
+    Without an explicit ``devices`` inventory the manager takes
+    ``jax.devices()`` the first time a pilot asks for devices, never before:
+    a manager whose pilots are all CPU-thread slots opens no backend, so a
+    process that holds the accelerator can fork workers that build one.
     """
 
     def __init__(self, devices: Optional[Sequence] = None,
@@ -138,18 +143,24 @@ class PilotManager:
         self._lock = threading.Lock()
         self._clock = as_clock(clock)
         self.heartbeat_timeout_s = heartbeat_timeout_s
-        self._all_devices = tuple(devices if devices is not None
-                                  else jax.devices())
-        self._free = list(self._all_devices)
+        self._free: Optional[list] = (list(devices) if devices is not None
+                                      else None)
         self._pilots: Dict[str, Pilot] = {}
         self._heartbeats: Dict[str, float] = {}
 
     # -- inventory ---------------------------------------------------------
 
+    def _free_list(self) -> list:
+        """The free-device pool, taking the backend's inventory on first
+        use (callers hold ``self._lock``)."""
+        if self._free is None:
+            self._free = list(jax.devices())
+        return self._free
+
     @property
     def free_devices(self) -> int:
         with self._lock:
-            return len(self._free)
+            return len(self._free_list())
 
     def pilots(self, tier: Optional[str] = None) -> List[Pilot]:
         with self._lock:
@@ -170,11 +181,12 @@ class PilotManager:
             devices: tuple = ()
             mesh = None
             if resource.n_devices > 0:
-                if len(self._free) < resource.n_devices:
+                free = self._free_list()
+                if len(free) < resource.n_devices:
                     raise PilotError(
                         f"admission failed: want {resource.n_devices} "
-                        f"devices, {len(self._free)} free")
-                devices = backend(resource, self._free[:resource.n_devices])
+                        f"devices, {len(free)} free")
+                devices = backend(resource, free[:resource.n_devices])
                 self._free = self._free[resource.n_devices:]
                 mesh = self._make_mesh(devices, resource)
             pid = f"pilot-{resource.tier}-{next(_pilot_ids)}"
@@ -235,7 +247,7 @@ class PilotManager:
             if n_devices is not None and res.n_devices != n_devices:
                 delta = n_devices - res.n_devices
                 if delta > 0:
-                    if len(self._free) < delta:
+                    if len(self._free_list()) < delta:
                         raise PilotError(
                             f"resize failed: want {delta} more devices, "
                             f"{len(self._free)} free")
@@ -259,7 +271,8 @@ class PilotManager:
             if pilot.state == "released":
                 return
             pilot.state = "released"
-            self._free.extend(pilot.devices)
+            if pilot.devices:
+                self._free.extend(pilot.devices)
             pilot.devices = ()
             pilot.mesh = None
 

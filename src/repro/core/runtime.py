@@ -81,6 +81,10 @@ class TaskFuture:
     def done(self) -> bool:
         return self._event.is_set()
 
+    def failed(self) -> bool:
+        """Done, with every attempt and retry spent on an error."""
+        return self._event.is_set() and self._error is not None
+
     def result(self, timeout: Optional[float] = None) -> Any:
         if not self._event.wait(timeout):
             raise TimeoutError(self.task_id)

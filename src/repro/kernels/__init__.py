@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the continuum's hot spots, their pure-jnp
+oracles (``ref.py``) and the import surface call sites use (``ops.py``)."""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Every Pallas entry point's ``interpret`` default: ``None`` runs the
+    compiled kernel on a TPU backend and the interpreter elsewhere, so a
+    kernel on the chip is interpreted only when a caller asks for it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = float("-inf")
 
 
@@ -95,11 +97,12 @@ def _pad_to(x, axis: int, mult: int):
     static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q (B,Sq,H,D); k/v (B,Sk,Hkv,D) -> (B,Sq,H,D).
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; on TPU pass ``interpret=False``.
+    ``interpret=None`` compiles the kernel on a TPU backend and runs the
+    kernel body in the interpreter elsewhere
+    (:func:`repro.kernels.resolve_interpret`).
     """
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
@@ -138,6 +141,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out[:, :, :sq, :].transpose(0, 2, 1, 3)

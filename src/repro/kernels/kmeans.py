@@ -23,9 +23,8 @@ Two entry points:
 Precision variants (the placement axis ``cost/calibrate.py`` prices):
 
 * ``fp32`` — everything float32.
-* ``bf16`` — points/centroids stored and fed to the MXU as bfloat16
-  (half the VMEM traffic), fp32 accumulation via
-  ``preferred_element_type``.
+* ``bf16`` — points/centroids stored as bfloat16 (half the HBM and VMEM
+  traffic), widened to fp32 in VMEM for the MXU.
 * ``int8`` — symmetric per-feature scales shared by points and
   centroids (:mod:`repro.kernels.quant`), int8 storage (quarter traffic),
   in-kernel dequantization, fp32 distance + sum accumulation.
@@ -46,7 +45,8 @@ winner per (shape, precision, backend) — the DES ``--profile`` workflow
 applied to the kernel grid.
 
 Validated in interpret mode against kernels/ref.py (assignment,
-fused-update and int8 oracles).
+fused-update and int8 oracles); ``interpret=None`` (the default) compiles
+the kernel on a TPU backend and interprets it elsewhere.
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import quant
+from repro.kernels import quant, resolve_interpret
 
 BIG = 1e30
 PRECISIONS = ("fp32", "bf16", "int8")
@@ -79,7 +79,14 @@ def _pad2(a, rows: int, cols: int, value=0):
 
 def _make_kernel(n: int, block_n: int, quantized: bool, fused: bool):
     """Build the grid kernel body.  ``n`` (static) is the true row count
-    — the fused accumulators mask padded tail rows with it."""
+    — the fused accumulators mask padded tail rows with it.
+
+    Both MXU dots run at ``Precision.HIGHEST``: the distance expansion
+    cancels terms ~10³ times an inlier's distance, and at the default
+    (one bf16 pass) precision the fp32 kernel lost 0.6% of assignments
+    against a float64 argmin on a TPU v5e.  The TPU compiler takes
+    ``HIGHEST`` on f32 operands only, so every storage dtype is widened
+    to f32 in VMEM; one code path serves all three precisions."""
 
     def kernel(*refs):
         if quantized:
@@ -91,19 +98,16 @@ def _make_kernel(n: int, block_n: int, quantized: bool, fused: bool):
         else:
             ids_ref, dmin_ref = out
 
+        x32 = pts_ref[...].astype(jnp.float32)
+        cm = cent_ref[...].astype(jnp.float32)
         if quantized:
             s = scale_ref[...]                        # (1, Fp) f32
-            xm = pts_ref[...].astype(jnp.float32) * s
-            cm = cent_ref[...].astype(jnp.float32) * s
-        else:
-            # storage dtype (f32 or bf16) straight into the MXU; the
-            # matmul accumulates f32 via preferred_element_type
-            xm = pts_ref[...]
-            cm = cent_ref[...]
-        x32 = xm.astype(jnp.float32)
+            x32 = x32 * s
+            cm = cm * s
         c2 = c2_ref[...]                              # (1, Kp) f32
         x2 = jnp.sum(x32 * x32, axis=1, keepdims=True)
-        xc = jax.lax.dot_general(xm, cm, (((1,), (1,)), ((), ())),
+        xc = jax.lax.dot_general(x32, cm, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
         d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)     # (bn, Kp)
         ids = jnp.argmin(d2, axis=1).astype(jnp.int32)
@@ -123,6 +127,7 @@ def _make_kernel(n: int, block_n: int, quantized: bool, fused: bool):
                            1.0, 0.0).astype(jnp.float32)
         # (Kp, bn) @ (bn, Fp) on the MXU: this block's per-centroid sums
         bs = jax.lax.dot_general(onehot, x32, (((0,), (0,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
         bc = jnp.sum(onehot, axis=0, keepdims=True)   # (1, Kp)
 
@@ -142,7 +147,7 @@ def _make_kernel(n: int, block_n: int, quantized: bool, fused: bool):
     return kernel
 
 
-def _call(points, centroids, *, block_n: int, interpret: bool,
+def _call(points, centroids, *, block_n: int, interpret,
           precision: str, fused: bool):
     if precision not in PRECISIONS:
         raise ValueError(f"precision must be one of {PRECISIONS}, "
@@ -167,8 +172,9 @@ def _call(points, centroids, *, block_n: int, interpret: bool,
                  if f != fp else scales[None, :]]
     elif precision == "bf16":
         pts = _pad2(ptsf, np_, fp).astype(jnp.bfloat16)
-        cent = _pad2(centf, kp, fp).astype(jnp.bfloat16)
-        centv = cent.astype(jnp.float32)[:k, :f]
+        # c2 must come from the rounded centroids the kernel holds
+        centv = quant.round_to_bf16(centf)
+        cent = _pad2(centv, kp, fp).astype(jnp.bfloat16)
     else:
         pts = _pad2(ptsf, np_, fp)
         cent = _pad2(centf, kp, fp)
@@ -199,7 +205,7 @@ def _call(points, centroids, *, block_n: int, interpret: bool,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pts, cent, *extra, c2)
     if fused:
         ids, dmin, sums, counts = res
@@ -211,7 +217,7 @@ def _call(points, centroids, *, block_n: int, interpret: bool,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret", "precision"))
 def kmeans_assign(points, centroids, *, block_n: int = 256,
-                  interpret: bool = True, precision: str = "fp32"):
+                  interpret=None, precision: str = "fp32"):
     """points (N,F), centroids (K,F) -> (ids (N,) int32, dmin (N,) f32)."""
     return _call(points, centroids, block_n=block_n, interpret=interpret,
                  precision=precision, fused=False)
@@ -220,7 +226,7 @@ def kmeans_assign(points, centroids, *, block_n: int = 256,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret", "precision"))
 def kmeans_assign_update(points, centroids, *, block_n: int = 256,
-                         interpret: bool = True, precision: str = "fp32"):
+                         interpret=None, precision: str = "fp32"):
     """The fused hot path: one grid pass returns
     ``(ids (N,), dmin (N,), sums (K,F) f32, counts (K,) f32)`` — the
     assignment *and* the per-centroid membership sums/counts a mini-batch
@@ -241,10 +247,9 @@ def autotune_block_n(n: int, f: int, k: int, *, precision: str = "fp32",
     deterministic columns."""
     import time as _time
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     pn = min(n, probe_n)
-    key = (pn, f, k, precision, bool(interpret), jax.default_backend())
+    key = (pn, f, k, precision, interpret, jax.default_backend())
     hit = _autotune_cache.get(key)
     if hit is not None:
         return hit

@@ -1,41 +1,12 @@
-"""Jit'd public wrappers for the Pallas kernels.
-
-Call sites use these (``from repro.kernels import ops as kops``); each
-forwards to the kernel with ``interpret=True`` on CPU hosts and
-``interpret=False`` on TPU, chosen at trace time from the default backend.
-"""
+"""The Pallas kernels call sites use (``from repro.kernels import ops as
+kops``).  Each entry point compiles the kernel on a TPU backend and runs
+it in the interpreter elsewhere (:func:`repro.kernels.resolve_interpret`);
+``interpret=True``/``False`` overrides that."""
 from __future__ import annotations
 
-import jax
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.kmeans import kmeans_assign, kmeans_assign_update
+from repro.kernels.ssd import ssd_chunk_scan
 
-from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.kmeans import kmeans_assign as _kmeans_assign
-from repro.kernels.kmeans import kmeans_assign_update as _kmeans_fused
-from repro.kernels.ssd import ssd_chunk_scan as _ssd
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    block_q: int = 128, block_k: int = 128):
-    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=_interpret())
-
-
-def kmeans_assign(points, centroids, *, block_n: int = 256,
-                  precision: str = "fp32"):
-    return _kmeans_assign(points, centroids, block_n=block_n,
-                          precision=precision, interpret=_interpret())
-
-
-def kmeans_assign_update(points, centroids, *, block_n: int = 256,
-                         precision: str = "fp32"):
-    """Fused assign+update: (ids, dmin, sums (K,F), counts (K,))."""
-    return _kmeans_fused(points, centroids, block_n=block_n,
-                         precision=precision, interpret=_interpret())
-
-
-def ssd_chunk_scan(xh, dt, A, B_, C_, D, *, chunk: int = 256):
-    return _ssd(xh, dt, A, B_, C_, D, chunk=chunk, interpret=_interpret())
+__all__ = ["flash_attention", "kmeans_assign", "kmeans_assign_update",
+           "ssd_chunk_scan"]

@@ -17,6 +17,7 @@ one rounding definition, so parity tests are exact.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 INT8_MAX = 127.0
@@ -48,3 +49,11 @@ def fake_quantize(x, scales):
     sees.  The jnp simulation path and the parity oracles both use this,
     so 'int8 kernel vs int8 reference' comparisons are bit-meaningful."""
     return dequantize(quantize(x, scales), scales)
+
+
+def round_to_bf16(x):
+    """The fp32 values a bf16 kernel holds: ``x`` rounded to bfloat16.
+    ``reduce_precision`` is kept by XLA, where a ``bf16 -> f32`` convert
+    pair may be folded away as excess precision."""
+    return jax.lax.reduce_precision(x.astype(jnp.float32), exponent_bits=8,
+                                    mantissa_bits=7)

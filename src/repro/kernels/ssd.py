@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _ssd_kernel(xh_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
                 y_ref, st_ref, cum_ref, *, chunk: int):
@@ -68,7 +70,7 @@ def _ssd_kernel(xh_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_scan(xh, dt, A, B_, C_, D, *, chunk: int = 256,
-                   interpret: bool = True):
+                   interpret=None):
     """Full SSD pass: Pallas intra-chunk kernel + host inter-chunk scan.
 
     xh (B,S,nh,hd); dt (B,S,nh) post-softplus; A (nh,) negative;
@@ -114,7 +116,7 @@ def ssd_chunk_scan(xh, dt, A, B_, C_, D, *, chunk: int = 256,
             jax.ShapeDtypeStruct((b, nh, nc, ds, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, nh, nc, chunk), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt, dtt, Bt, Ct, A2, D2)
 
     # ---- inter-chunk recurrence (sequential, host-side jnp) ----

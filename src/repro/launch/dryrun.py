@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import SHAPES, get_arch, list_archs
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.launch.mesh import make_production_mesh, make_rules
@@ -94,7 +93,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     dtype = jnp.bfloat16
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return _lower_cell_inner(cfg, shape, arch_name, shape_name, mesh,
                                  chips, rules, dtype, t0, overrides,
                                  verbose, compression, pipeline)

@@ -57,8 +57,8 @@ def _precision_view(centroids, points, precision: str):
     if precision == "fp32":
         return centroids, points
     if precision == "bf16":
-        return (centroids.astype(jnp.bfloat16).astype(jnp.float32),
-                points.astype(jnp.bfloat16).astype(jnp.float32))
+        from repro.kernels import quant
+        return quant.round_to_bf16(centroids), quant.round_to_bf16(points)
     if precision == "int8":
         from repro.kernels import quant
         scales = quant.symmetric_scales(points, centroids)
@@ -69,10 +69,15 @@ def _precision_view(centroids, points, precision: str):
 
 
 def _expansion_assign(centroids, points):
-    # ||x-c||^2 = ||x||^2 - 2 x.c + ||c||^2 (MXU-matmul form)
+    # ||x-c||^2 = ||x||^2 - 2 x.c + ||c||^2 (MXU-matmul form).  The
+    # expansion cancels terms far larger than the distance, so x.c takes
+    # the full-precision passes: at the TPU's default (bf16-operand)
+    # precision an inlier's distance is off by several units
     x2 = jnp.sum(points * points, axis=1, keepdims=True)
     c2 = jnp.sum(centroids * centroids, axis=1)
-    d2 = x2 - 2.0 * points @ centroids.T + c2[None, :]
+    d2 = x2 - 2.0 * jnp.matmul(points, centroids.T,
+                               precision=jax.lax.Precision.HIGHEST) \
+        + c2[None, :]
     d2 = jnp.maximum(d2, 0.0)
     ids = jnp.argmin(d2, axis=1)
     dmin = jnp.sqrt(jnp.take_along_axis(d2, ids[:, None], axis=1)[:, 0])
@@ -111,7 +116,8 @@ def _assign_update(centroids, counts, points, impl: str = "fused",
             # materialization and a (K,N)@(N,F) matmul
             onehot = jax.nn.one_hot(ids, k, dtype=jnp.float32)
             batch_counts = onehot.sum(0)                      # (K,)
-            sums = onehot.T @ pv                              # (K,F)
+            sums = jnp.matmul(onehot.T, pv,                   # (K,F)
+                              precision=jax.lax.Precision.HIGHEST)
         else:
             # fused jnp: same distance pass, scatter-add membership stats
             # — the one-pass formulation the Pallas kernel implements on

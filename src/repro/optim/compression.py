@@ -20,8 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def int8_compress(g):
     """Per-tensor symmetric int8 quantization. Returns (q, scale)."""
@@ -56,7 +54,7 @@ def compressed_psum(g, axis_name: str, error: jnp.ndarray | None = None):
     g32 = g.astype(jnp.float32)
     if error is not None:
         g32 = g32 + error.astype(jnp.float32)
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     shape = g32.shape
     n = g32.size
     pad = (-n) % p

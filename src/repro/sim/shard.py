@@ -538,7 +538,7 @@ def build_scale_shard(cfg: dict) -> ShardRunner:
     n_dev, n_con = hi - lo, chi - clo
     clock = SimClock()
     metrics = MetricsRegistry(clock=clock, streaming=cfg["streaming"])
-    mgr = PilotManager()
+    mgr = PilotManager(devices=())
     edge = mgr.submit_pilot(ComputeResource(tier="edge", n_workers=n_dev))
     cloud = mgr.submit_pilot(ComputeResource(tier="cloud", n_workers=n_con))
     payload = bytes(cfg["payload_bytes"])
@@ -653,7 +653,7 @@ def build_tier_cut_shard(cfg: dict) -> ShardRunner:
     bw, rtt = cfg["bandwidth_bps"], cfg["rtt_s"]
     clock = SimClock()
     metrics = MetricsRegistry(clock=clock)
-    mgr = PilotManager()
+    mgr = PilotManager(devices=())
     edge = mgr.submit_pilot(ComputeResource(tier="edge",
                                             n_workers=max(devices, 1)))
     cloud = mgr.submit_pilot(ComputeResource(tier="cloud",

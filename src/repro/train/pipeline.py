@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
 from repro.models import layers as L
@@ -170,7 +169,7 @@ def make_pp_train_step(cfg: ArchConfig, tc: TS.TrainConfig,
         # opt state mirrors params: anything under 'blocks' stage-sharded
         sspec = {"opt": _opt_specs(state["opt"], pc), "step": P()}
         bspec = {k: P() for k in batch}
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, sspec, bspec),
             out_specs=(pspec, sspec, {"loss": P(), "grad_norm": P()}),

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
 from repro.optim import clip_by_global_norm, cosine_schedule, make_optimizer
@@ -226,7 +225,7 @@ def make_compressed_train_step(cfg: ArchConfig, tc: TrainConfig,
                 bspec[k] = P(None, "pod")
             else:
                 bspec[k] = P(*("pod",) + (None,) * (v.ndim - 1))
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs_of(params), specs_of(state), bspec),
             out_specs=(specs_of(params), specs_of(state),

@@ -2,32 +2,17 @@
 tests must see the real single CPU device (the 512-device override belongs
 exclusively to launch/dryrun.py).
 
-The jit-heavy tests dominate tier-1 wall time, so a persistent XLA
-compilation cache is enabled (keyed by HLO hash; disable with
+The jit-heavy tests dominate tier-1 wall time, so the persistent XLA
+compilation cache is enabled (:mod:`repro.compile_cache`; disable with
 REPRO_NO_JAX_CACHE=1).  First runs pay full compile cost; reruns and CI
 with a restored cache directory get the compile time back.
 """
-import os
-
 import numpy as np
 import pytest
 
+from repro.compile_cache import enable_compilation_cache
 
-def _enable_jax_compilation_cache():
-    if os.environ.get("REPRO_NO_JAX_CACHE"):
-        return
-    try:
-        import jax
-        cache_dir = os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR",
-            os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass                     # older jax without the cache: run without
-
-
-_enable_jax_compilation_cache()
+enable_compilation_cache()
 
 
 @pytest.fixture

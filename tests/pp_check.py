@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import get_arch
 from repro.models import transformer as T
 from repro.train import step as TS
@@ -49,7 +48,7 @@ def main():
 
     # --- pipeline: same init, blocks reshaped to (S, L/S, ...) ---
     pp_params, pp_state = init_pp_state(key, cfg, tc, pc)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pp_step = make_pp_train_step(cfg, tc, pc, rules, mesh)
         pp_p2, _, pp_metrics = pp_step(pp_params, pp_state, batch)
     pp_loss = float(pp_metrics["loss"])

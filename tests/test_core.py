@@ -409,6 +409,30 @@ def test_pipeline_consumer_fault_recovers():
     assert res.metrics.counter("runtime.retries") == 1
 
 
+def test_pipeline_run_ends_when_a_stage_has_failed():
+    """A handler that always raises fails every consumer task for good;
+    the run ends then, not at its timeout, and says which stage died."""
+    mgr = PilotManager()
+    edge = mgr.submit_pilot(ComputeResource(tier="edge", n_workers=2))
+    cloud = mgr.submit_pilot(ComputeResource(tier="cloud", n_workers=2))
+
+    def broken(ctx, data=None):
+        raise RuntimeError("broken handler")
+
+    pipe = EdgeToCloudPipeline(
+        pilot_cloud_processing=cloud, pilot_edge=edge,
+        produce_function_handler=lambda ctx: np.zeros((4, 2)),
+        process_cloud_function_handler=broken, max_retries=1)
+    t0 = time.monotonic()
+    res = pipe.run(n_messages=20, timeout_s=600)
+    assert time.monotonic() - t0 < 30
+    assert res.n_processed == 0 and res.n_produced == 20
+    assert res.metrics.counter("runtime.task_errors") == 4   # 2 tasks x 2
+    assert [e["stage"] for e in res.metrics.events("run_aborted")] == \
+        ["process_cloud"]
+    mgr.release_all()
+
+
 def test_pipeline_runs_under_manual_simclock():
     """The threaded pipeline accepts a manually driven SimClock: a driver
     thread plays time while run() executes, metrics land on virtual

@@ -160,6 +160,23 @@ def test_ops_wrappers():
     assert ids.shape == (100,) and dmin.shape == (100,)
 
 
+def test_kernel_entry_points_resolve_interpret_from_backend(monkeypatch):
+    """No Pallas entry point defaults to interpreting: ``interpret=None``
+    compiles on a TPU backend and interprets only elsewhere."""
+    import inspect
+
+    import repro.kernels as K
+    from repro.kernels.kmeans import kmeans_assign_update
+    for fn in (flash_attention, kmeans_assign, kmeans_assign_update,
+               ssd_chunk_scan):
+        assert inspect.signature(fn).parameters["interpret"].default is None
+    assert K.resolve_interpret(None) is True          # this CPU backend
+    assert K.resolve_interpret(False) is False
+    monkeypatch.setattr(K.jax, "default_backend", lambda: "tpu")
+    assert K.resolve_interpret(None) is False
+    assert K.resolve_interpret(True) is True
+
+
 def test_model_uses_pallas_attention():
     """gqa_forward(impl='pallas') matches impl='dense'."""
     from repro.configs import get_arch

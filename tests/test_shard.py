@@ -8,6 +8,7 @@ container has no ``hypothesis``): seed-parametrized
 """
 import math
 
+import jax
 import numpy as np
 import pytest
 
@@ -193,6 +194,28 @@ def test_shard_mp_matches_inline():
     b = _sharded_cell(2, mode="mp")
     for key in _DET_KEYS:
         assert a[key] == b[key]
+
+
+def test_des_shards_fork_without_touching_the_backend(monkeypatch):
+    """A parent that holds the accelerator can fork the DES shards: pilots
+    without devices and the 2-shard path never ask JAX for its devices,
+    while a pilot that wants a device still gets one."""
+    real_devices = jax.devices
+
+    def no_backend(*args, **kwargs):
+        raise AssertionError("jax.devices() on a path that needs no device")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    mgr = PilotManager()
+    edge = mgr.submit_pilot(ComputeResource(tier="edge", n_workers=2))
+    cloud = mgr.submit_pilot(ComputeResource(tier="cloud", n_workers=2))
+    assert edge.devices == () and cloud.devices == ()
+    row = _sharded_cell(2, mode="mp")
+    assert row["processed"] == 2000
+    monkeypatch.setattr(jax, "devices", real_devices)
+    mesh_pilot = mgr.submit_pilot(ComputeResource(tier="cloud", n_devices=1))
+    assert len(mesh_pilot.devices) == 1
+    mgr.release_all()
 
 
 def test_shard_streaming_sketch_merge_identical():

@@ -103,9 +103,8 @@ def test_compressed_psum_shard_map_single_device():
         return avg, err
 
     from jax.sharding import PartitionSpec as P
-    from repro import compat
-    fn = compat.shard_map(body, mesh=mesh, in_specs=P(),
-                          out_specs=(P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(),
+                       out_specs=(P(), P()), check_vma=False)
     avg, err = fn(g)
     scale = float(jnp.max(jnp.abs(g))) / 127.0
     assert float(jnp.max(jnp.abs(avg - g))) <= scale / 2 + 1e-6

@@ -28,27 +28,14 @@ import json
 import sys
 
 
-def _enable_compilation_cache() -> None:
-    """Mirror tests/conftest.py: persist XLA compiles under .jax_cache so
-    CI's restored cache actually shortens the kernel measurements."""
-    import os
-
-    import jax
-    try:
-        cache_dir = os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR",
-            os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass                     # older jax without the cache: run without
-
-
 def drift_report(models=None, n_messages: int = 5, tier: str = "cloud"):
     """Refit each model live and pair the numbers with the committed
     calibration.  Returns ``{"meta": ..., "models": [row, ...]}``."""
-    _enable_compilation_cache()
+    from repro.compile_cache import enable_compilation_cache
     from repro.cost.calibrate import Calibrator, load_calibration
+    # persist XLA compiles so CI's restored cache actually shortens the
+    # kernel measurements
+    enable_compilation_cache()
     committed = load_calibration()
     cal = Calibrator()
     rows = []
