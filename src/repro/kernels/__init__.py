@@ -1,5 +1,6 @@
-"""Pallas TPU kernels for the continuum's hot spots, their pure-jnp
-oracles (``ref.py``) and the import surface call sites use (``ops.py``)."""
+"""Pallas TPU kernels for the continuum's hot spots and their pure-jnp
+oracles (``ref.py``).  Call sites use each kernel's entry point directly;
+its ``interpret`` default resolves here."""
 from __future__ import annotations
 
 from typing import Optional

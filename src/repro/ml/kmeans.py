@@ -23,7 +23,7 @@ Implementation axis (``impl``):
   ``cost/calibrate.py`` rooflines, and the HLO-visible proxy for the
   fused Pallas kernel (custom-calls are free to the HLO cost model).
 * ``"pallas"`` — the fused Pallas TPU kernel
-  (:func:`repro.kernels.ops.kmeans_assign_update`).
+  (:func:`repro.kernels.kmeans.kmeans_assign_update`).
 * ``"jnp"`` — the historical two-pass path (assign, then an (N,K) one-hot
   matmul).  Kept as the parity/benchmark baseline.
 
@@ -48,6 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import kmeans as pallas_kmeans
+from repro.kernels import quant
+
 IMPLS = ("fused", "pallas", "jnp")
 PRECISIONS = ("fp32", "bf16", "int8")
 
@@ -57,10 +60,8 @@ def _precision_view(centroids, points, precision: str):
     if precision == "fp32":
         return centroids, points
     if precision == "bf16":
-        from repro.kernels import quant
         return quant.round_to_bf16(centroids), quant.round_to_bf16(points)
     if precision == "int8":
-        from repro.kernels import quant
         scales = quant.symmetric_scales(points, centroids)
         return (quant.fake_quantize(centroids, scales),
                 quant.fake_quantize(points, scales))
@@ -88,8 +89,8 @@ def _expansion_assign(centroids, points):
 def _assign(centroids, points, impl: str = "fused",
             precision: str = "fp32"):
     if impl == "pallas":
-        from repro.kernels import ops as kops
-        return kops.kmeans_assign(points, centroids, precision=precision)
+        return pallas_kmeans.kmeans_assign(points, centroids,
+                                           precision=precision)
     centroids, points = _precision_view(centroids, points, precision)
     return _expansion_assign(centroids, points)
 
@@ -101,8 +102,7 @@ def _assign_update(centroids, counts, points, impl: str = "fused",
     ``(new_centroids, new_counts, ids, dmin)``."""
     k = centroids.shape[0]
     if impl == "pallas":
-        from repro.kernels import ops as kops
-        ids, dmin, sums, batch_counts = kops.kmeans_assign_update(
+        ids, dmin, sums, batch_counts = pallas_kmeans.kmeans_assign_update(
             points, centroids, precision=precision)
     else:
         # sums accumulate the *precision view* of the points, not the raw

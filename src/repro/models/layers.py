@@ -247,8 +247,8 @@ def gqa_forward(p, x, cos, sin, cfg: ArchConfig, *, impl="dense",
         o = attention_chunked(q, k, v, causal=True, window=window,
                               chunk_q=min(chunk, s), chunk_k=min(chunk, s))
     elif impl == "pallas":
-        from repro.kernels import ops as kops
-        o = kops.flash_attention(q, k, v, causal=True, window=window)
+        from repro.kernels.flash_attention import flash_attention
+        o = flash_attention(q, k, v, causal=True, window=window)
     else:
         raise ValueError(impl)
     return o.reshape(b, s, h * hd) @ p["wo"], (k, v)
@@ -708,9 +708,9 @@ def ssm_forward(p, x, cfg: ArchConfig, *, return_state=False, impl="jnp"):
                          + p["dt_bias"][None, None, :])
     A = -jnp.exp(p["A_log"])
     if impl == "pallas":
-        from repro.kernels import ops as kops
-        y, final = kops.ssd_chunk_scan(xh, dt, A, B_, C_, p["D"],
-                                       chunk=min(s.chunk, sl))
+        from repro.kernels.ssd import ssd_chunk_scan
+        y, final = ssd_chunk_scan(xh, dt, A, B_, C_, p["D"],
+                                  chunk=min(s.chunk, sl))
     else:
         y, final = ssd_chunked(xh, dt, A, B_, C_, p["D"], min(s.chunk, sl),
                                return_state=True)

@@ -5,9 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.kmeans import kmeans_assign
+from repro.kernels.kmeans import kmeans_assign, kmeans_assign_update
 from repro.kernels.ssd import ssd_chunk_scan
 
 RNG = np.random.default_rng(42)
@@ -147,16 +147,18 @@ def test_ssd_matches_layers_impl():
 
 
 # ---------------------------------------------------------------------------
-# ops wrappers route correctly
+# entry points route correctly with their default interpret
 # ---------------------------------------------------------------------------
 
 def test_ops_wrappers():
+    """Called as the model and detector call sites call them (no
+    ``interpret``), the entry points run on this backend."""
     q = jnp.asarray(RNG.standard_normal((1, 128, 2, 64)), jnp.float32)
-    out = ops.flash_attention(q, q, q)
+    out = flash_attention(q, q, q)
     assert out.shape == q.shape
     pts = jnp.asarray(RNG.standard_normal((100, 32)), jnp.float32)
     cent = jnp.asarray(RNG.standard_normal((25, 32)), jnp.float32)
-    ids, dmin = ops.kmeans_assign(pts, cent)
+    ids, dmin = kmeans_assign(pts, cent)
     assert ids.shape == (100,) and dmin.shape == (100,)
 
 
@@ -166,7 +168,6 @@ def test_kernel_entry_points_resolve_interpret_from_backend(monkeypatch):
     import inspect
 
     import repro.kernels as K
-    from repro.kernels.kmeans import kmeans_assign_update
     for fn in (flash_attention, kmeans_assign, kmeans_assign_update,
                ssd_chunk_scan):
         assert inspect.signature(fn).parameters["interpret"].default is None
@@ -248,7 +249,7 @@ def test_kmeans_fused_kernel_counts_every_point():
     """Padded tail rows must not leak into the accumulators: counts sum
     to exactly n for a deliberately non-block-aligned n."""
     pts, cent = _blob(257, 7, 3)
-    ids, dmin, sums, counts = ops.kmeans_assign_update(pts, cent)
+    ids, dmin, sums, counts = kmeans_assign_update(pts, cent)
     assert float(jnp.sum(counts)) == 257.0
     np.testing.assert_allclose(
         np.asarray(jnp.sum(sums, axis=0)), np.asarray(jnp.sum(pts, axis=0)),

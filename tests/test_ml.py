@@ -120,16 +120,15 @@ def test_kmeans_update_threads_impl_to_fused_kernel(monkeypatch):
     """Satellite bugfix regression: KMeans(impl='pallas').update() must
     reach the fused Pallas kernel — historically _update re-ran _assign
     with the *default* impl, silently bypassing it."""
-    import repro.kernels.ops as kops
-    from repro.ml import kmeans as mlk
+    import repro.kernels.kmeans as pallas_kmeans
     calls = []
-    real = kops.kmeans_assign_update
+    real = pallas_kmeans.kmeans_assign_update
 
     def counting(*a, **kw):
         calls.append(1)
         return real(*a, **kw)
 
-    monkeypatch.setattr(kops, "kmeans_assign_update", counting)
+    monkeypatch.setattr(pallas_kmeans, "kmeans_assign_update", counting)
     jax.clear_caches()                 # force a retrace through the spy
     gen = MiniAppGenerator(n_points=300, seed=5)
     pts = gen.sample()
