@@ -223,9 +223,11 @@ class Topic:
     def produce(self, payload: Any, *, key: Optional[str] = None,
                 partition: Optional[int] = None,
                 msg_id: Optional[str] = None) -> Message:
-        raw = _serialize(payload)
         if msg_id is None:
             msg_id = f"{self.name}-{next(_msg_counter)}"
+        with self.metrics.span("pilot.serialize", msg_id) as sp:
+            raw = _serialize(payload)
+            sp.nbytes = len(raw)
         if partition is None:
             if key is not None:
                 partition = hash(key) % self.n_partitions
@@ -246,7 +248,7 @@ class Topic:
             part.append_unlocked(msg, now + delay)
         else:
             part.append(msg, now + delay)
-        self.metrics.stamp(msg_id, "broker_in", wan_delay_s=delay)
+        self.metrics.stamp(msg_id, "broker_in")
         self.metrics.incr(f"topic.{self.name}.bytes_in", msg.nbytes)
         self.metrics.incr(f"topic.{self.name}.msgs_in")
         if delay > 0.0:
@@ -315,9 +317,7 @@ class Topic:
                                          min(ready - now, deadline - now))
                         continue
                     msg = part.log[idx]
-                    self.metrics.stamp(
-                        msg.msg_id, "broker_out",
-                        visible_at=ready)
+                    self.metrics.stamp(msg.msg_id, "broker_out")
                     return msg
                 remaining = deadline - now
                 if remaining <= 0:
@@ -352,7 +352,7 @@ class Topic:
         if self._honor_visibility() and self._clock.now() < ready:
             return None, ready
         msg = log[offset]
-        self.metrics.stamp(msg.msg_id, "broker_out", visible_at=ready)
+        self.metrics.stamp(msg.msg_id, "broker_out")
         return msg, None
 
     def _poll_nowait_at(self, part: _Partition, partition: int, offset: int
@@ -369,7 +369,7 @@ class Topic:
         if self._honor_visibility() and self._clock.now() < ready:
             return None, ready
         msg = part.log[idx]
-        self.metrics.stamp(msg.msg_id, "broker_out", visible_at=ready)
+        self.metrics.stamp(msg.msg_id, "broker_out")
         return msg, None
 
     def end_offsets(self) -> List[int]:
@@ -521,8 +521,7 @@ class ConsumerGroup:
                 if off < end.base + len(end.log):
                     msg = self.topic.poll(p, off, timeout_s=0.01)
                     if msg is not None:
-                        self.topic.metrics.stamp(msg.msg_id, "consumed",
-                                                 consumer=consumer_id)
+                        self.topic.metrics.stamp(msg.msg_id, "consumed")
                         return msg
             if timeout_s == 0:
                 return None
@@ -540,8 +539,7 @@ class ConsumerGroup:
             off = self.committed[p]     # int list read: GIL-atomic
             msg, ready = self.topic.poll_nowait(p, off)
             if msg is not None:
-                self.topic.metrics.stamp(msg.msg_id, "consumed",
-                                         consumer=consumer_id)
+                self.topic.metrics.stamp(msg.msg_id, "consumed")
                 return msg, None
             if ready is not None:
                 next_ready = ready if next_ready is None \

@@ -291,6 +291,8 @@ class ThreadedExecutor:
             with state.lock:
                 state.idle[stage] = state.idle.get(stage, 0) + 1
 
+        metrics = pipe.metrics
+
         def interpret(ctx: TaskContext, eff: Any) -> Any:
             if isinstance(eff, Sleep):
                 clock.sleep(max(eff.seconds, 0.0))
@@ -328,8 +330,12 @@ class ThreadedExecutor:
                         state.idle[eff.stage] = \
                             state.idle.get(eff.stage, 0) + 1
                 try:
-                    return eff.group.poll(eff.consumer_id,
-                                          timeout_s=eff.timeout_s)
+                    with metrics.span("pilot.poll") as sp:
+                        msg = eff.group.poll(eff.consumer_id,
+                                             timeout_s=eff.timeout_s)
+                        if msg is not None:
+                            sp.msg_id = msg.msg_id
+                    return msg
                 finally:
                     if eff.stage is not None:
                         with state.lock:

@@ -520,12 +520,16 @@ class ContinuumPipeline:
             inflight_key = (stage_idx, cid, ctx.attempt)
             inflight[inflight_key] = msg.msg_id
             try:
-                data = msg.value()
+                # spans open and close between yields: the SimExecutor
+                # interleaves every body on one thread
+                with metrics.span("pilot.deserialize", msg.msg_id):
+                    data = msg.value()
                 svc.payload = data
                 yield svc
                 svc.payload = None
                 fn = self._fn(stage_name)
-                out = fn(ctx, data=data)
+                with metrics.span("pilot.handler", msg.msg_id):
+                    out = fn(ctx, data=data)
             except BaseException:
                 # release the dedup reservation so the redelivery (from
                 # this task's retry or a rebalance) is processed, then let
@@ -537,24 +541,26 @@ class ContinuumPipeline:
             # hop identity: forwarded messages carry the originating
             # msg_id in their key so the final stamp links end to end
             origin = msg.key or msg.msg_id
-            if final:
-                metrics.stamp(origin, "processed", bytes=msg.nbytes)
-                commit(msg)
-                inflight.pop(inflight_key, None)
-                with state.lock:
-                    state.n_processed += 1
-                    if state.collect:
-                        state.results.append(out)
-                    if (state.n_processed >= state.n_messages
-                            and state.t_done is None):
-                        state.t_done = now()
-                        state.stop.set()
-                state.processed_sem.release()
-            else:
-                out_topic.produce(out, key=origin, partition=msg.partition,
-                                  msg_id=f"{origin}+h{stage_idx}")
-                commit(msg)
-                inflight.pop(inflight_key, None)
+            with metrics.span("pilot.commit", msg.msg_id):
+                if final:
+                    metrics.stamp(origin, "processed", bytes=msg.nbytes)
+                    commit(msg)
+                    inflight.pop(inflight_key, None)
+                    with state.lock:
+                        state.n_processed += 1
+                        if state.collect:
+                            state.results.append(out)
+                        if (state.n_processed >= state.n_messages
+                                and state.t_done is None):
+                            state.t_done = now()
+                            state.stop.set()
+                    state.processed_sem.release()
+                else:
+                    out_topic.produce(out, key=origin,
+                                      partition=msg.partition,
+                                      msg_id=f"{origin}+h{stage_idx}")
+                    commit(msg)
+                    inflight.pop(inflight_key, None)
             heartbeat()
 
     # -- run -------------------------------------------------------------------

@@ -13,6 +13,18 @@ per-hop latencies exactly like the paper's linked metrics. Counters and
 gauges cover throughput and resource accounting (bytes through the broker,
 task retries, straggler re-executions).
 
+Spans time the work *inside* a hop: ``registry.span(name, msg_id=...,
+nbytes=...)`` brackets one step of the served path (serialize, poll,
+deserialize, handler, commit; inside the handler the device step, the
+pull of its result and the parameter-service publish).  Recording is off
+by default, and then a span is one attribute check and a shared no-op
+context.  ``record_spans()`` turns it on: every closed span is kept as a
+:class:`SpanRecord` on the registry's clock, and opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a running device
+trace shows each span on its own clock too.  Code that holds no registry
+(the detectors' handlers) opens its spans with the module-level
+:func:`span`, which joins the span open on the current thread.
+
 Thread-safe: producers/consumers/runtimes stamp from their own threads.
 
 Two storage modes:
@@ -33,12 +45,13 @@ Two storage modes:
 """
 from __future__ import annotations
 
+import itertools
 import math
 import statistics
 import threading
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from repro.sim.clock import NULL_LOCK, as_clock
 
@@ -182,6 +195,95 @@ class _EventStats:
         self.bytes = 0.0
 
 
+class SpanRecord(NamedTuple):
+    """One closed span: ``start``/``end`` on the registry's clock,
+    ``thread`` the ident of the thread it ran on, ``parent`` the ``id`` of
+    the span that enclosed it on that thread (``None`` at top level).
+    ``msg_id`` is the message it worked on (inherited from the parent when
+    not given); ``nbytes`` the bytes it moved, where given."""
+    id: int
+    parent: Optional[int]
+    name: str
+    start: float
+    end: float
+    thread: int
+    msg_id: Optional[str]
+    nbytes: Optional[int]
+
+
+class _NoSpan:
+    """The context every span is while recording is off.  Its ``msg_id``
+    and ``nbytes`` take and keep nothing, for spans that learn them only
+    inside."""
+
+    __slots__ = ()
+    msg_id = nbytes = property(lambda self: None, lambda self, value: None)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+_span_ids = itertools.count()
+
+
+class _OpenSpans(threading.local):
+    """The spans open on each thread, innermost last."""
+
+    def __init__(self):
+        self.stack: List["_Span"] = []
+
+
+_open = _OpenSpans()
+
+
+class _Span:
+    __slots__ = ("registry", "name", "msg_id", "nbytes", "_id", "_parent",
+                 "_start", "_annotation")
+
+    def __init__(self, registry: "MetricsRegistry", name: str,
+                 msg_id: Optional[str], nbytes: Optional[int]):
+        self.registry = registry
+        self.name = name
+        self.msg_id = msg_id
+        self.nbytes = nbytes
+
+    def __enter__(self):
+        stack = _open.stack
+        parent = stack[-1] if stack else None
+        self._parent = parent._id if parent is not None else None
+        if self.msg_id is None and parent is not None:
+            self.msg_id = parent.msg_id
+        self._id = next(_span_ids)
+        stack.append(self)
+        self._annotation = self.registry._annotation(self.name)
+        self._annotation.__enter__()
+        self._start = self.registry._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = self.registry._clock()
+        self._annotation.__exit__(*exc)
+        _open.stack.pop()
+        self.registry._span_records.append(SpanRecord(
+            self._id, self._parent, self.name, self._start, end,
+            threading.get_ident(), self.msg_id, self.nbytes))
+        return False
+
+
+def span(name: str, nbytes: Optional[int] = None):
+    """A span inside the one open on this thread, recorded by that span's
+    registry under its ``msg_id``; a no-op when no span is open (recording
+    off, or code running outside the served path)."""
+    stack = _open.stack
+    if not stack:
+        return _NO_SPAN
+    return _Span(stack[-1].registry, name, None, nbytes)
+
+
 class MetricsRegistry:
     """Process-wide registry: message traces + counters + gauges.
 
@@ -216,6 +318,28 @@ class MetricsRegistry:
         self._sketches: Dict[Tuple[str, str], LatencySketch] = {}
         self._estats: Dict[str, _EventStats] = {}
         self._retired = 0
+        # jax.profiler.TraceAnnotation while recording spans, else None
+        self._annotation = None
+        self._span_records: List[SpanRecord] = []
+
+    # -- spans -----------------------------------------------------------------
+
+    def record_spans(self) -> None:
+        """Turn span recording on."""
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+
+    def span(self, name: str, msg_id: Optional[str] = None,
+             nbytes: Optional[int] = None):
+        """Context manager timing ``name``; records nothing unless
+        :meth:`record_spans` turned recording on."""
+        if self._annotation is None:
+            return _NO_SPAN
+        return _Span(self, name, msg_id, nbytes)
+
+    def spans(self) -> List[SpanRecord]:
+        """Every span closed while recording, in the order they closed."""
+        return list(self._span_records)
 
     # -- message lifecycle ---------------------------------------------------
 

@@ -8,7 +8,7 @@ Implementation: a versioned, thread-safe pytree store.
 
 * ``publish(name, tree)`` — store a new version (monotonic version numbers);
   values are host-side numpy copies so publishers can keep mutating device
-  arrays.
+  arrays.  The copy is the ``pilot.publish`` span, with its bytes.
 * ``fetch(name)`` / ``fetch_if_newer(name, have_version)`` — consumers poll
   for updates (the paper's model-update pattern: the inference task refreshes
   its model when the trainer publishes).
@@ -31,13 +31,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core.monitoring import span
+
 
 @dataclass
 class _Entry:
     version: int
     tree: Any
     published_at: float
-    nbytes: int
 
 
 def _to_host(tree):
@@ -59,17 +60,17 @@ class ParameterService:
         self.metrics = metrics
 
     def publish(self, name: str, tree: Any) -> int:
-        host_tree = _to_host(tree)
-        nbytes = _tree_bytes(host_tree)
+        # the host copy waits for the computation that produced the tree
+        opener = span if self.metrics is None else self.metrics.span
+        with opener("pilot.publish") as sp:
+            host_tree = _to_host(tree)
+            sp.nbytes = _tree_bytes(host_tree)
         with self._lock:
             version = (self._store[name].version + 1
                        if name in self._store else 1)
             self._store[name] = _Entry(version, host_tree,
-                                       time.monotonic(), nbytes)
+                                       time.monotonic())
             subs = list(self._subs.get(name, ()))
-        if self.metrics is not None:
-            self.metrics.incr(f"params.{name}.publishes")
-            self.metrics.incr(f"params.{name}.bytes", nbytes)
         for cb in subs:
             cb(version, host_tree)
         return version

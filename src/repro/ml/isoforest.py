@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.monitoring import span
+
 EULER_GAMMA = 0.5772156649015329
 
 
@@ -185,13 +187,17 @@ class IsolationForest:
                 v, tree = param_service.fetch(model_name)
                 holder["state"] = jax.tree.map(jnp.asarray, tree)
                 holder["version"] = v
+            # fit and score are each handed the points as float32
             if train or holder["state"] is None:
-                holder["state"] = self.fit(pts)
+                with span("pilot.step", nbytes=pts.size * 4):
+                    holder["state"] = self.fit(pts)
                 if param_service is not None:
                     holder["version"] = param_service.publish(
                         model_name, holder["state"])
-            scores = np.asarray(
-                self.outlier_scores(holder["state"], pts))
+            with span("pilot.step", nbytes=pts.size * 4):
+                scores = self.outlier_scores(holder["state"], pts)
+            with span("pilot.pull", nbytes=scores.nbytes):
+                scores = np.asarray(scores)
             return {"n_outliers": int((scores > 0.6).sum()),
                     "mean_score": float(scores.mean())}
 

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.monitoring import span
 from repro.kernels import kmeans as pallas_kmeans
 from repro.kernels import quant
 
@@ -217,15 +218,18 @@ class KMeans:
                 if newer is not None:
                     holder["version"] = newer[0]
                     holder["state"] = jax.tree.map(jnp.asarray, newer[1])
-            if train:
-                holder["state"], _, scores = self.assign_update(
-                    holder["state"], pts)
-                if param_service is not None:
-                    holder["version"] = param_service.publish(
-                        model_name, holder["state"])
-            else:
-                scores = self.outlier_scores(holder["state"], pts)
-            s = np.asarray(scores)
+            # the step is handed the points as float32
+            with span("pilot.step", nbytes=pts.size * 4):
+                if train:
+                    holder["state"], _, scores = self.assign_update(
+                        holder["state"], pts)
+                else:
+                    scores = self.outlier_scores(holder["state"], pts)
+            if train and param_service is not None:
+                holder["version"] = param_service.publish(
+                    model_name, holder["state"])
+            with span("pilot.pull", nbytes=scores.nbytes):
+                s = np.asarray(scores)
             thresh = s.mean() + 3.0 * s.std()
             return {"n_outliers": int((s > thresh).sum()),
                     "mean_score": float(s.mean())}
