@@ -8,6 +8,7 @@ so only the worker given this file loads the TPU compiler.  Every test that
 needs it lives in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,11 +103,31 @@ def test_isoforest_fit_compiles_for_v5e(forest_key, message):
     assert compiled.memory_analysis() is not None
 
 
-def test_isoforest_score_compiles_for_v5e(one_chip, forest_key, message):
+@pytest.fixture(scope="module")
+def compiled_score(one_chip, forest_key, message):
     forest = _on(one_chip, jax.eval_shape(
         lambda k, x: isoforest._fit(k, x, FOREST.n_trees, FOREST.psi,
                                     FOREST.max_depth), forest_key, message))
     psi = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    compiled = isoforest._score.lower(forest, message, psi,
-                                      FOREST.max_depth).compile()
-    assert compiled.memory_analysis() is not None
+    return isoforest._score.lower(forest, message, psi,
+                                  FOREST.max_depth).compile()
+
+
+def test_isoforest_score_compiles_for_v5e(compiled_score):
+    assert compiled_score.memory_analysis() is not None
+
+
+# Reading at 10,000 x 32 with 100 trees of 256: temp_size_in_bytes 0 (the
+# level state stays in on-chip memory).  One level's one-hot materialized
+# over its 16 nodes would already take 64 MB.
+SCORE_TEMP_LIMIT = 32 * 2**20
+
+
+def test_isoforest_score_walks_levels_without_gathers(compiled_score):
+    """The score looks nodes and features up with one-hot selects: the
+    program holds no gather and no loop, and no one-hot is materialized."""
+    text = compiled_score.as_text()
+    assert re.search(r"\bgather\(", text) is None
+    assert re.search(r"\bwhile\(", text) is None
+    assert compiled_score.memory_analysis().temp_size_in_bytes \
+        < SCORE_TEMP_LIMIT
