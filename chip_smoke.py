@@ -68,12 +68,16 @@ KERNEL_MAX_SUMS_ERR = 1e-2        # ||sums - oracle||_F / ||oracle||_F
 
 # Pipeline phase, per message: (n_outliers absolute, mean_score relative)
 # against the highest-precision replay.  The isolation forest does no matmul,
-# so it must match exactly; the auto-encoder's matmuls run at the chip's
-# default precision when served, and its training drifts further.
+# so it must match exactly.  The auto-encoder takes every product at
+# Precision.HIGHEST and its replay runs the same two programs on the same
+# chip: on a v5e, with each epoch in the Pallas kernel, it read 0 and 0.0
+# over the phase's 32 messages of 10,000 points, warm-started through
+# 902,400 Adam steps.  1e-6 leaves room for a sum order only, against the
+# 4e-7 to 3e-5 that bf16 products move the first mean score.
 PIPELINE_TOL = {
     "kmeans-pallas": (1, 1e-3),
     "kmeans-fused": (1, 1e-3),
-    "autoencoder": (10, 2e-2),
+    "autoencoder": (0, 1e-6),
     "isoforest": (0, 1e-5),
 }
 

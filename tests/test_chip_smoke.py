@@ -47,7 +47,7 @@ def test_smoke_kernel_phase_at_tiny_size(smoke):
 @pytest.mark.parametrize("name,served,reference", [
     ("kmeans-pallas", KMeans(impl="pallas"), KMeans(impl="jnp")),
     ("kmeans-fused", KMeans(), KMeans(impl="jnp")),
-    ("autoencoder", AutoEncoder(), AutoEncoder()),
+    ("autoencoder", AutoEncoder(epochs=2), AutoEncoder(epochs=2)),
     ("isoforest", IsolationForest(n_trees=4), IsolationForest(n_trees=4)),
 ])
 def test_smoke_pipeline_phase_at_tiny_size(smoke, name, served, reference):

@@ -34,7 +34,8 @@ def test_ae_param_count_is_papers_11552():
 def test_ae_learns_and_detects():
     gen = MiniAppGenerator(n_points=2000, outlier_frac=0.02, seed=2)
     pts, is_out = gen.sample_with_labels()
-    ae = AutoEncoder()
+    # one epoch a message (PyOD's 100 would be 228,000 CPU steps here)
+    ae = AutoEncoder(epochs=1)
     st = ae.init()
     losses = []
     for _ in range(40):

@@ -16,8 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.kmeans import kmeans_assign_update
-from repro.ml import isoforest
-from repro.ml.autoencoder import AutoEncoder
+from repro.ml import autoencoder, isoforest
 
 N_KERNEL, N_MESSAGE, N_FEATURES, N_CLUSTERS = 1_000_000, 10_000, 32, 25
 
@@ -82,11 +81,16 @@ def test_kmeans_fused_kernel_compiles_for_v5e(one_chip, precision):
 
 
 def test_autoencoder_train_step_compiles_for_v5e(one_chip, message):
-    ae = AutoEncoder()
+    """The whole-message fit: 100 epochs of 282 Adam steps in one
+    program, each epoch one Pallas kernel."""
+    ae = autoencoder.AutoEncoder()
     state = _on(one_chip, jax.eval_shape(ae.init))
-    compiled = ae._step.lower(state["params"], state["opt"], state["step"],
-                              message).compile()
+    key = _on(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = autoencoder._ae_train.lower(
+        state, message, key, ae.schedule(N_MESSAGE),
+        interpret=False).compile()
     assert compiled.memory_analysis() is not None
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 FOREST = isoforest.IsolationForest(n_trees=100)
