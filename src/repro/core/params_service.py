@@ -8,7 +8,10 @@ Implementation: a versioned, thread-safe pytree store.
 
 * ``publish(name, tree)`` — store a new version (monotonic version numbers);
   values are host-side numpy copies so publishers can keep mutating device
-  arrays.  The copy is the ``pilot.publish`` span, with its bytes.
+  arrays.  The copy is the ``pilot.publish`` span, with its bytes; the
+  registry counts the leaves published (``params.leaves_published``) and
+  those whose device copy was started ahead of the first read
+  (``params.leaves_copied_async``).
 * ``fetch(name)`` / ``fetch_if_newer(name, have_version)`` — consumers poll
   for updates (the paper's model-update pattern: the inference task refreshes
   its model when the trainer publishes).
@@ -42,9 +45,18 @@ class _Entry:
 
 
 def _to_host(tree):
-    # np.array(copy=True): published versions must be snapshots, immune to
-    # later in-place mutation by the publisher
-    return jax.tree.map(lambda x: np.array(x, copy=True), tree)
+    """Host snapshot of ``tree`` as ``(snapshot, n_leaves, n_started)``.
+
+    ``jax.device_get`` starts every device leaf's copy before it reads
+    any, so the host waits once for the tree and not once per leaf;
+    ``n_started`` counts those leaves.  np.array(copy=True): published
+    versions must be snapshots, writable and immune to later in-place
+    mutation by the publisher."""
+    leaves = jax.tree.leaves(tree)
+    started = sum(hasattr(x, "copy_to_host_async") for x in leaves)
+    host = jax.tree.map(lambda x: np.array(x, copy=True),
+                        jax.device_get(tree))
+    return host, len(leaves), started
 
 
 def _tree_bytes(tree) -> int:
@@ -63,8 +75,11 @@ class ParameterService:
         # the host copy waits for the computation that produced the tree
         opener = span if self.metrics is None else self.metrics.span
         with opener("pilot.publish") as sp:
-            host_tree = _to_host(tree)
+            host_tree, n_leaves, n_started = _to_host(tree)
             sp.nbytes = _tree_bytes(host_tree)
+        if self.metrics is not None:
+            self.metrics.incr("params.leaves_published", n_leaves)
+            self.metrics.incr("params.leaves_copied_async", n_started)
         with self._lock:
             version = (self._store[name].version + 1
                        if name in self._store else 1)

@@ -421,10 +421,13 @@ class AutoEncoder:
                 else:
                     holder["state"] = self.init()
             # the score is handed the points as float32; the fit reads
-            # them where the score left them, on the device
+            # them where the score left them, on the device.  The scores'
+            # host copy starts as the score is dispatched, so the pull
+            # finds them on the host
             with span("pilot.step", nbytes=pts.size * 4):
                 x = jnp.asarray(pts, jnp.float32)
                 scores = _ae_score(holder["state"]["params"], x)
+                scores.copy_to_host_async()
             if train:
                 schedule = self.schedule(len(pts))
                 with span("pilot.step", nbytes=0):

@@ -218,13 +218,15 @@ class KMeans:
                 if newer is not None:
                     holder["version"] = newer[0]
                     holder["state"] = jax.tree.map(jnp.asarray, newer[1])
-            # the step is handed the points as float32
+            # the step is handed the points as float32; the scores' host
+            # copy starts as the step is dispatched
             with span("pilot.step", nbytes=pts.size * 4):
                 if train:
                     holder["state"], _, scores = self.assign_update(
                         holder["state"], pts)
                 else:
                     scores = self.outlier_scores(holder["state"], pts)
+                scores.copy_to_host_async()
             if train and param_service is not None:
                 holder["version"] = param_service.publish(
                     model_name, holder["state"])
