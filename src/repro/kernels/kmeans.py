@@ -16,9 +16,8 @@ Two entry points:
   in-register and accumulates per-centroid point sums (one more
   (K × block_n) @ (block_n × F) MXU matmul) and counts into accumulator
   outputs that live in VMEM across the sequential grid steps (constant
-  index_map).  This eliminates the historical second pass in
-  ``ml/kmeans.py::_update`` — materializing an (N × K) one-hot and
-  re-running assignment — which used to dominate the per-message flops.
+  index_map).  This eliminates the second pass of the two-pass update
+  (``KMeans(impl="jnp")``), which materializes an (N × K) one-hot.
 
 Precision variants (the placement axis ``cost/calibrate.py`` prices):
 

@@ -73,8 +73,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.monitoring import span
 from repro.kernels import resolve_interpret
+from repro.ml import handler
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -401,49 +401,27 @@ class AutoEncoder:
     def outlier_scores(self, state, points):
         return _ae_score(state["params"], jnp.asarray(points, jnp.float32))
 
+    def first_state(self, points):
+        return self.init()
+
+    def step(self, state, x, train: bool, metrics):
+        """Score the message with the held model, then fit on it when
+        training; counts each fit's Adam steps and epochs in ``metrics``
+        (``ae.adam_steps``, ``ae.epochs``)."""
+        scores = _ae_score(state["params"], x)
+        if train:
+            schedule = self.schedule(len(x))
+            state = _ae_train(state, x, self._key, schedule)
+            if metrics is not None:
+                metrics.incr("ae.adam_steps",
+                             schedule.epochs * schedule.n_batches)
+                metrics.incr("ae.epochs", schedule.epochs)
+        return state, scores
+
+    def n_outliers(self, scores) -> int:
+        thresh = np.percentile(scores, 100.0 * (1.0 - CONTAMINATION))
+        return int((scores > thresh).sum())
+
     def make_processor(self, param_service=None, model_name: str = "ae",
                        train: bool = True):
-        """FaaS ``process_cloud`` handler: score the message with the held
-        model, fit on it, publish the state, answer.  Counts the Adam steps
-        and epochs of each fit in the parameter service's registry
-        (``ae.adam_steps``, ``ae.epochs``)."""
-        holder = {"state": None, "version": 0}
-        metrics = getattr(param_service, "metrics", None)
-
-        def process_cloud(context, data=None):
-            pts = np.asarray(data, np.float64)
-            if holder["state"] is None:
-                if (param_service is not None
-                        and model_name in param_service.names()):
-                    v, tree = param_service.fetch(model_name)
-                    holder["state"] = jax.tree.map(jnp.asarray, tree)
-                    holder["version"] = v
-                else:
-                    holder["state"] = self.init()
-            # the score is handed the points as float32; the fit reads
-            # them where the score left them, on the device.  The scores'
-            # host copy starts as the score is dispatched, so the pull
-            # finds them on the host
-            with span("pilot.step", nbytes=pts.size * 4):
-                x = jnp.asarray(pts, jnp.float32)
-                scores = _ae_score(holder["state"]["params"], x)
-                scores.copy_to_host_async()
-            if train:
-                schedule = self.schedule(len(pts))
-                with span("pilot.step", nbytes=0):
-                    holder["state"] = _ae_train(holder["state"], x,
-                                                self._key, schedule)
-                if metrics is not None:
-                    metrics.incr("ae.adam_steps",
-                                 schedule.epochs * schedule.n_batches)
-                    metrics.incr("ae.epochs", schedule.epochs)
-                if param_service is not None:
-                    holder["version"] = param_service.publish(
-                        model_name, holder["state"])
-            with span("pilot.pull", nbytes=scores.nbytes):
-                s = np.asarray(scores)
-            thresh = np.percentile(s, 100.0 * (1.0 - CONTAMINATION))
-            return {"n_outliers": int((s > thresh).sum()),
-                    "mean_score": float(s.mean())}
-
-        return process_cloud
+        return handler.make_processor(self, param_service, model_name, train)

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.monitoring import span
+from repro.ml import handler
 
 EULER_GAMMA = 0.5772156649015329
 
@@ -213,31 +213,20 @@ class IsolationForest:
         pts = jnp.asarray(points, jnp.float32)
         return _score(state["forest"], pts, state["psi"], self.max_depth)
 
+    def first_state(self, points):
+        return None                     # the first step fits
+
+    def step(self, state, x, train: bool, metrics):
+        """Refit on the message when training (the paper's streaming
+        model-update pattern: 100 ensemble tasks a message), then score
+        it."""
+        if train or state is None:
+            state = self.fit(x)
+        return state, self.outlier_scores(state, x)
+
+    def n_outliers(self, scores) -> int:
+        return int((scores > 0.6).sum())
+
     def make_processor(self, param_service=None, model_name: str = "iforest",
                        train: bool = True):
-        """FaaS handler: refit on each message (the paper's streaming
-        model-update pattern — 100 ensemble tasks per message)."""
-        holder = {"state": None, "version": 0}
-
-        def process_cloud(context, data=None):
-            pts = np.asarray(data, np.float64)
-            if holder["state"] is None and param_service is not None \
-                    and model_name in param_service.names():
-                v, tree = param_service.fetch(model_name)
-                holder["state"] = jax.tree.map(jnp.asarray, tree)
-                holder["version"] = v
-            # fit and score are each handed the points as float32
-            if train or holder["state"] is None:
-                with span("pilot.step", nbytes=pts.size * 4):
-                    holder["state"] = self.fit(pts)
-                if param_service is not None:
-                    holder["version"] = param_service.publish(
-                        model_name, holder["state"])
-            with span("pilot.step", nbytes=pts.size * 4):
-                scores = self.outlier_scores(holder["state"], pts)
-            with span("pilot.pull", nbytes=scores.nbytes):
-                scores = np.asarray(scores)
-            return {"n_outliers": int((scores > 0.6).sum()),
-                    "mean_score": float(scores.mean())}
-
-        return process_cloud
+        return handler.make_processor(self, param_service, model_name, train)

@@ -48,9 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.monitoring import span
 from repro.kernels import kmeans as pallas_kmeans
 from repro.kernels import quant
+from repro.ml import handler
 
 IMPLS = ("fused", "pallas", "jnp")
 PRECISIONS = ("fp32", "bf16", "int8")
@@ -135,17 +135,6 @@ def _assign_update(centroids, counts, points, impl: str = "fused",
     return new_centroids, new_counts, ids, dmin
 
 
-def _update(centroids, counts, points, impl: str = "fused",
-            precision: str = "fp32"):
-    """Mini-batch k-means step (per-count learning rate).  Threads
-    ``impl``/``precision`` through to the fused step — historically this
-    re-ran ``_assign`` with the *default* impl, silently bypassing the
-    Pallas kernel for ``KMeans(impl='pallas')`` updates."""
-    new_centroids, new_counts, _, _ = _assign_update(
-        centroids, counts, points, impl=impl, precision=precision)
-    return new_centroids, new_counts
-
-
 @dataclass
 class KMeans:
     n_clusters: int = 25
@@ -173,13 +162,14 @@ class KMeans:
 
     def update(self, state, points):
         pts = jnp.asarray(points, jnp.float32)
-        cent, counts = _update(state["centroids"], state["counts"], pts,
-                               impl=self.impl, precision=self.precision)
+        cent, counts, _, _ = _assign_update(
+            state["centroids"], state["counts"], pts,
+            impl=self.impl, precision=self.precision)
         return {"centroids": cent, "counts": counts}
 
     def assign_update(self, state, points):
         """One fused pass: (new_state, ids, dmin) — the streaming hot
-        path ``make_processor`` runs per message."""
+        path the served handler runs per message."""
         pts = jnp.asarray(points, jnp.float32)
         cent, counts, ids, dmin = _assign_update(
             state["centroids"], state["counts"], pts,
@@ -194,49 +184,23 @@ class KMeans:
         _, d = self.assign(state, points)
         return float(jnp.sum(d * d))
 
+    def first_state(self, points):
+        return self.init(points)
+
+    def step(self, state, x, train: bool, metrics):
+        """Training messages take the fused path: one assign+update pass
+        yields the new centroids and the outlier scores together."""
+        if train:
+            state, _, scores = self.assign_update(state, x)
+            return state, scores
+        return state, self.outlier_scores(state, x)
+
+    def n_outliers(self, scores) -> int:
+        return int((scores > scores.mean() + 3.0 * scores.std()).sum())
+
     def make_processor(self, param_service=None, model_name: str = "kmeans",
                        train: bool = True):
-        """FaaS ``process_cloud`` handler: score + (optionally) update +
-        publish to the parameter service — the paper's model-update loop.
-        Training messages take the *fused* path: one assign+update pass
-        yields the outlier scores and the centroid step together."""
-        holder = {"state": None, "version": 0}
-
-        def process_cloud(context, data=None):
-            pts = np.asarray(data, np.float64)
-            if holder["state"] is None:
-                if param_service is not None and model_name in \
-                        param_service.names():
-                    v, tree = param_service.fetch(model_name)
-                    holder["state"] = jax.tree.map(jnp.asarray, tree)
-                    holder["version"] = v
-                else:
-                    holder["state"] = self.init(pts)
-            elif param_service is not None:
-                newer = param_service.fetch_if_newer(
-                    model_name, holder["version"])
-                if newer is not None:
-                    holder["version"] = newer[0]
-                    holder["state"] = jax.tree.map(jnp.asarray, newer[1])
-            # the step is handed the points as float32; the scores' host
-            # copy starts as the step is dispatched
-            with span("pilot.step", nbytes=pts.size * 4):
-                if train:
-                    holder["state"], _, scores = self.assign_update(
-                        holder["state"], pts)
-                else:
-                    scores = self.outlier_scores(holder["state"], pts)
-                scores.copy_to_host_async()
-            if train and param_service is not None:
-                holder["version"] = param_service.publish(
-                    model_name, holder["state"])
-            with span("pilot.pull", nbytes=scores.nbytes):
-                s = np.asarray(scores)
-            thresh = s.mean() + 3.0 * s.std()
-            return {"n_outliers": int((s > thresh).sum()),
-                    "mean_score": float(s.mean())}
-
-        return process_cloud
+        return handler.make_processor(self, param_service, model_name, train)
 
 
 def assignment_agreement(precision: str, *, n_points: int = 2_500,

@@ -97,24 +97,107 @@ def test_isoforest_separates_outliers():
     assert auc > 0.85
 
 
-def test_processors_share_via_param_service():
+# the served detectors at a tiny size, over 4 features
+SHARED = {
+    "autoencoder": lambda: AutoEncoder(n_features=4, epochs=1),
+    "isoforest": lambda: IsolationForest(n_trees=4, psi=32),
+    "kmeans": lambda: KMeans(n_clusters=5, n_features=4),
+}
+
+
+class _Ctx:
+    attempt = 0
+
+
+@pytest.mark.parametrize("name", sorted(SHARED))
+def test_processors_share_via_param_service(name):
+    """A ``train=False`` handler scores with the model a training handler
+    published, picks up each newer version, and publishes nothing."""
     from repro.core import ParameterService
     ps = ParameterService()
-    km = KMeans(n_clusters=5, n_features=4)
+    det = SHARED[name]()
     gen = MiniAppGenerator(n_points=200, n_features=4, n_clusters=5,
                            seed=0)
+    proc_a = det.make_processor(ps, "m")
+    proc_b = det.make_processor(ps, "m", train=False)
+    proc_a(_Ctx(), data=gen.sample())
+    for version in (1, 2):
+        assert ps.version("m") == version
+        pts = gen.sample()
+        published = jax.tree.map(jnp.asarray, ps.fetch("m")[1])
+        want = np.asarray(det.outlier_scores(published, pts))
+        out = proc_b(_Ctx(), data=pts)
+        assert out == {"n_outliers": det.n_outliers(want),
+                       "mean_score": float(want.mean())}
+        assert ps.version("m") == version   # train=False published nothing
+        proc_a(_Ctx(), data=gen.sample())
 
-    class Ctx:
-        attempt = 0
 
-    proc_a = km.make_processor(ps, "m")
-    proc_a(Ctx(), data=gen.sample())
-    assert ps.version("m") == 1
-    # a second (fresh) processor picks up the published model
-    proc_b = km.make_processor(ps, "m", train=False)
-    out = proc_b(Ctx(), data=gen.sample())
-    assert "n_outliers" in out
-    assert ps.version("m") == 1     # train=False published nothing
+class _MeanShift:
+    """A detector the served handler has never seen: its state is the
+    running mean of the points, its score a point's distance to it."""
+
+    def __init__(self):
+        self.first_points = []
+
+    def first_state(self, points):
+        self.first_points.append(points)
+        return {"mean": jnp.zeros(points.shape[1], jnp.float32), "n": 0}
+
+    def step(self, state, x, train, metrics):
+        scores = jnp.linalg.norm(x - state["mean"], axis=1)
+        if train:
+            n = state["n"] + 1
+            state = {"mean": state["mean"] + (x.mean(0) - state["mean"]) / n,
+                     "n": n}
+            if metrics is not None:
+                metrics.incr("shift.fits")
+        return state, scores
+
+    def n_outliers(self, scores):
+        return int((scores > 3.0).sum())
+
+
+def test_a_new_detector_needs_only_its_state_step_and_threshold():
+    from repro.core import MetricsRegistry, ParameterService
+    from repro.ml import handler
+    metrics = MetricsRegistry()
+    metrics.record_spans()
+    ps = ParameterService(metrics=metrics)
+    det = _MeanShift()
+    proc = handler.make_processor(det, ps, "shift")
+    rng = np.random.default_rng(0)
+    msgs = [rng.standard_normal((50, 3)) + 1.0 for _ in range(3)]
+    mean = np.zeros(3, np.float32)
+    for i, pts in enumerate(msgs, start=1):
+        with metrics.span("pilot.handler", msg_id=f"m{i}"):
+            out = proc(_Ctx(), data=pts)
+        x = pts.astype(np.float32)
+        scores = np.linalg.norm(x - mean, axis=1)
+        assert out["n_outliers"] == int((scores > 3.0).sum())
+        assert out["mean_score"] == pytest.approx(float(scores.mean()),
+                                                  rel=1e-5)
+        mean = mean + (x.mean(0) - mean) / i
+        version, tree = ps.fetch("shift")
+        assert version == i and tree["n"] == i
+        np.testing.assert_allclose(tree["mean"], mean, rtol=1e-5)
+    # the state is made once, from the first message's float64 points
+    assert len(det.first_points) == 1
+    assert det.first_points[0].dtype == np.float64
+    assert metrics.counter("shift.fits") == 3
+    counts = {}
+    for r in metrics.spans():
+        counts.setdefault(r.msg_id, []).append((r.name, r.nbytes))
+    for i in range(1, 4):
+        assert sorted(counts[f"m{i}"]) == sorted([
+            ("pilot.handler", None), ("pilot.step", 50 * 3 * 4),
+            ("pilot.publish", 3 * 4 + 8), ("pilot.pull", 50 * 4)])
+    # a second handler takes the published model, not a first state
+    out = handler.make_processor(det, ps, "shift", train=False)(
+        _Ctx(), data=msgs[0])
+    assert len(det.first_points) == 1 and ps.version("shift") == 3
+    scores = np.linalg.norm(msgs[0].astype(np.float32) - mean, axis=1)
+    assert out["mean_score"] == pytest.approx(float(scores.mean()), rel=1e-5)
 
 
 def test_kmeans_update_threads_impl_to_fused_kernel(monkeypatch):
