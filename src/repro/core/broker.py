@@ -17,9 +17,9 @@ durability (the checkpoint layer owns durability; see DESIGN.md §2). So:
   RTT, 60–100 Mbit/s iPerf band) with a token bucket + latency stamp —
   the paper's geographic-distribution experiment (Fig 3 right).
 
-Serialization is real (numpy ``tobytes``): message size on the wire equals
-the paper's 8 B/point accounting, and the WAN shaper charges the actual
-serialized bytes.
+Serialization is real (the ``.npy`` bytes ``np.save`` writes): message size
+on the wire equals the paper's 8 B/point accounting, and the WAN shaper
+charges the actual serialized bytes.
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+import numpy.lib.format as npy_format
 
 from repro.core.monitoring import MetricsRegistry
 from repro.sim.clock import Clock, as_clock
@@ -42,16 +43,52 @@ from repro.sim.clock import Clock, as_clock
 _msg_counter = itertools.count()
 
 
-def _serialize(payload: Any) -> bytes:
-    """numpy-first serialization; sizes match the paper's 8 B/float64 points."""
+def _npy_direct(a: np.ndarray) -> Optional[bytes]:
+    """The bytes ``np.save(buf, a, allow_pickle=False)`` writes, built as its
+    version 1.0 header plus one copy of ``a``'s memory.  ``np.save`` into a
+    ``BytesIO`` copies the array four or five times (buffered ``nditer``,
+    ``tobytes``, the growing buffer, ``getvalue``), all under the GIL.
+    None where ``np.save``'s own path is needed: a non-contiguous array, an
+    object or user-defined dtype (``np.save`` raises for those), a zero
+    itemsize, or a header ``np.save`` would write at a later version."""
+    # np.save's own test for pickling, then a buffer of no bytes
+    if (a.dtype.hasobject or not getattr(type(a.dtype), "_legacy", False)
+            or a.itemsize == 0):
+        return None
+    if a.flags.c_contiguous:
+        data = a
+    elif a.flags.f_contiguous:
+        data = a.T      # np.save writes a Fortran-ordered array's memory as is
+    else:
+        return None
+    head = io.BytesIO()
+    try:
+        npy_format.write_array_header_1_0(
+            head, npy_format.header_data_from_array_1_0(a))
+    except ValueError:  # too long, or not latin-1: np.save picks 2.0 or 3.0
+        return None
+    flat = np.ndarray.view(data, np.ndarray).reshape(-1).view(np.uint8)
+    return b"".join((head.getvalue(), flat))
+
+
+def _encode(payload: Any) -> Tuple[bytes, bool]:
+    """numpy-first serialization; sizes match the paper's 8 B/float64 points.
+    Also says whether an array's bytes were written directly."""
     if isinstance(payload, np.ndarray):
+        raw = _npy_direct(payload)
+        if raw is not None:
+            return raw, True
         buf = io.BytesIO()
         np.save(buf, payload, allow_pickle=False)
-        return buf.getvalue()
+        return buf.getvalue(), False
     if isinstance(payload, bytes):
-        return payload
+        return payload, False
     import pickle
-    return pickle.dumps(payload)
+    return pickle.dumps(payload), False
+
+
+def _serialize(payload: Any) -> bytes:
+    return _encode(payload)[0]
 
 
 def _deserialize(raw: bytes) -> Any:
@@ -226,8 +263,10 @@ class Topic:
         if msg_id is None:
             msg_id = f"{self.name}-{next(_msg_counter)}"
         with self.metrics.span("pilot.serialize", msg_id) as sp:
-            raw = _serialize(payload)
+            raw, direct = _encode(payload)
             sp.nbytes = len(raw)
+        if direct:
+            self.metrics.incr(f"topic.{self.name}.arrays_encoded_direct")
         if partition is None:
             if key is not None:
                 partition = hash(key) % self.n_partitions
